@@ -32,6 +32,8 @@ def main(argv=None):
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_comm, bench_constellation, bench_faults,
                             bench_frameworks, bench_kernels, bench_round,
                             bench_security, bench_vqc, roofline)

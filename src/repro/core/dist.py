@@ -140,8 +140,8 @@ def _wmean_sats(tree, w):
     wn = w / jnp.maximum(jnp.sum(w), 1e-9)
 
     def red(x):
-        m = jnp.tensordot(wn.astype(jnp.float32),
-                          x.astype(jnp.float32), axes=(0, 0))
+        m = jnp.tensordot(wn.astype(jnp.float32), x.astype(jnp.float32),
+                          axes=(0, 0), precision=jax.lax.Precision.HIGHEST)
         return jnp.broadcast_to(m[None], x.shape).astype(x.dtype)
 
     return jax.tree_util.tree_map(red, tree)
@@ -356,6 +356,31 @@ def fl_init_state(model_cfg, api, optimizer, n_sats: int, key) -> FLState:
                    stale=stale,
                    stale_age=jnp.full((n_sats,), -1, jnp.int32),
                    round_idx=jnp.zeros((), jnp.int32))
+
+
+def stack_client_data(sat_data: list) -> dict:
+    """Per-satellite datasets -> one (n_sat, n, ...) array per field,
+    every client cut to the shortest one's length n."""
+    n = min(len(next(iter(d.values()))) for d in sat_data)
+    return {k: jnp.stack([d[k][:n] for d in sat_data]) for k in sat_data[0]}
+
+
+def sample_round_batches(data: dict, rng: np.random.Generator, steps: int,
+                         batch_size: int) -> dict:
+    """One round's ``batches`` for ``round_fn``: leaves (n_sat, steps,
+    batch_size, ...), drawn uniformly from each satellite's own rows of
+    the stacked ``data`` — one gather per field."""
+    n_sat, n = next(iter(data.values())).shape[:2]
+    idx = jnp.asarray(rng.integers(0, n, (n_sat, steps, batch_size)))
+    return {k: jax.vmap(lambda x, i: x[i])(v, idx) for k, v in data.items()}
+
+
+def sat_axis_shardings(tree, mesh):
+    """A NamedSharding per leaf: the leading (satellite) axis split over
+    the mesh's "data" axis, scalars replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return jax.tree_util.tree_map(
+        lambda x: NamedSharding(mesh, P("data") if x.shape else P()), tree)
 
 
 def fl_input_specs(model_cfg, api, fl: SatQFLConfig, n_sats: int,

@@ -97,6 +97,10 @@ from repro.security.otp import (decrypt_tree, decrypt_tree_rows, encrypt_tree,
                                 tree_to_u32, tree_to_u32_rows)
 from repro.quantum.teleport import teleport_params
 
+# FedAvg reductions run in full f32: the TPU's default matmul precision
+# rounds f32 operands to bf16, which would quantize the global model
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 # receiver-side batched MAC check — module-level so tests can simulate a
 # tampered stage. NOTE: it is read at TRACE time of _secure_stage_impl, so
@@ -814,8 +818,9 @@ class SatQFLTrainer:
         wn = jnp.asarray(w, jnp.float32)
         wn = wn / jnp.maximum(jnp.sum(wn), 1e-9)
         return jax.tree_util.tree_map(
-            lambda x: jnp.tensordot(wn, x.astype(jnp.float32),
-                                    axes=(0, 0)).astype(x.dtype), stacked)
+            lambda x: jnp.tensordot(wn, x.astype(jnp.float32), axes=(0, 0),
+                                    precision=HIGHEST).astype(x.dtype),
+            stacked)
 
     # ------------------------------------------------------------------
     # per-mode group schedulers (per-client oracle) — each merges one
@@ -1005,7 +1010,8 @@ class SatQFLTrainer:
         keep = jnp.asarray(empty)
 
         def _merge(x, g):
-            m = jnp.tensordot(an, x.astype(jnp.float32), axes=(1, 0))
+            m = jnp.tensordot(an, x.astype(jnp.float32), axes=(1, 0),
+                              precision=HIGHEST)
             k = keep.reshape((-1,) + (1,) * (x.ndim - 1))
             return jnp.where(k, g.astype(jnp.float32), m).astype(x.dtype)
 
@@ -1039,7 +1045,7 @@ class SatQFLTrainer:
         def one(x, g):
             xf = x.reshape((nd,) + x.shape[2:]).astype(jnp.float32)
             xb = jnp.broadcast_to(xf[None], (mp,) + xf.shape)
-            out = jnp.einsum('gk,gk...->g...', w2, xb)
+            out = jnp.einsum('gk,gk...->g...', w2, xb, precision=HIGHEST)
             k = anyv.reshape((-1,) + (1,) * (out.ndim - 1))
             return jnp.where(k, out,
                              g.astype(jnp.float32)[None]).astype(x.dtype)
@@ -1054,8 +1060,8 @@ class SatQFLTrainer:
 
         def one(x):
             frame = jnp.zeros((nd,) + x.shape[1:], x.dtype).at[rows].set(x)
-            return jnp.einsum('k,k...->...', wf,
-                              frame.astype(jnp.float32)).astype(x.dtype)
+            return jnp.einsum('k,k...->...', wf, frame.astype(jnp.float32),
+                              precision=HIGHEST).astype(x.dtype)
 
         return jax.tree_util.tree_map(one, entries)
 

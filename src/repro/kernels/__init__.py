@@ -9,7 +9,8 @@
   ssd_scan      — Mamba-2 SSD chunked scan (mamba2 + hymba branch)
 
 Each subpackage: ``kernel.py`` (pl.pallas_call + BlockSpec), ``ops.py``
-(jit'd public wrapper incl. interpret-mode switch for this CPU container),
+(jit'd public wrapper; ``interpret=None`` interprets only on the CPU
+backend — ``backend.resolve_interpret``),
 ``ref.py`` (pure-jnp oracle; also the backward path where the kernel is
 forward-only). Tests sweep shapes/dtypes and assert allclose vs ref.
 """

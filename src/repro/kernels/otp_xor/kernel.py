@@ -15,7 +15,9 @@ the ALU:HBM ratio, so the fused kernel stays bandwidth-bound and the MAC is
 Layout: msg/pad (n_blocks, R, C) uint32 with (R, C) = (block_rows, 128);
 powers (2, R, C): powers[0] for the lo-16 symbol of each word, powers[1]
 for the hi-16 symbol (global symbol order lo, hi, lo, hi, ...). Out: ct
-same shape; tags (n_blocks, 1, 1).
+same shape; per-lane partial tags (n_blocks, 1, 128) — the block's rows
+are reduced in the kernel, its 128 lanes by ``lane_tags`` after it, so
+every output block stays lane-dense.
 """
 from __future__ import annotations
 
@@ -24,6 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve_interpret
 
 P31 = 0x7FFFFFFF        # python ints: pallas kernels cannot
 MASK16 = 0xFFFF         # capture traced scalar constants
@@ -52,31 +56,36 @@ def _mulmod(a, b):
     return acc
 
 
-def _sum_mod_all(v):
-    """Modular reduction of a (R, C) tile to a scalar in TWO plain sums.
+def _sum_mod(v, axis: int):
+    """Exact modular sum of terms < p along ``axis`` (kept as size 1).
 
-    Each term is < p = 2^31: split into 16-bit halves and sum each half
-    exactly in uint32 (lo ≤ n·(2^16−1), hi ≤ n·(2^15−1) — both < 2^32 for
-    n ≤ 2^16 words), then fold hi·2^16 back mod p. Replaces the old
-    log-depth pairwise-addmod tree: 2 vectorized reductions instead of
-    ~14 sequential halving steps.
+    Each term is split into 16-bit halves, which are summed in int32 —
+    the TPU reduces signed integers only — exactly for up to 2^15 terms
+    (lo <= n·(2^16−1), hi <= n·(2^15−1), both < 2^31); hi·2^16 is then
+    folded back mod p. Two vectorized reductions, no sequential tree.
     """
-    flat = v.reshape(-1)
-    assert flat.shape[0] <= (1 << 16), "tile too large for exact u32 sums"
-    s_lo = jnp.sum(flat & MASK16)
-    s_hi = jnp.sum(flat >> 16)
-    return _addmod(_mod31(s_lo), _mulmod(_mod31(s_hi), jnp.uint32(1 << 16)))
+    assert v.shape[axis] <= (1 << 15), "too many terms for exact i32 sums"
+    s_lo = jnp.sum((v & MASK16).astype(jnp.int32), axis=axis, keepdims=True)
+    s_hi = jnp.sum((v >> 16).astype(jnp.int32), axis=axis, keepdims=True)
+    return _addmod(_mod31(s_lo.astype(jnp.uint32)),
+                   _mulmod(_mod31(s_hi.astype(jnp.uint32)),
+                           jnp.uint32(1 << 16)))
+
+
+def lane_tags(parts):
+    """(..., 1, 128) per-lane partial tags -> (...) per-block tags."""
+    return _sum_mod(parts[..., 0, :], -1)[..., 0]
 
 
 def _otp_mac_kernel(msg_ref, pad_ref, pw_ref, ct_ref, tag_ref):
-    msg = msg_ref[...]
-    pad = pad_ref[...]
+    msg = msg_ref[0]
+    pad = pad_ref[0]
     ct = msg ^ pad
-    ct_ref[...] = ct
+    ct_ref[0] = ct
     lo = (ct & MASK16) + 1          # MAC symbols (+1 padding-proof)
     hi = (ct >> 16) + 1
     terms = _addmod(_mulmod(lo, pw_ref[0]), _mulmod(hi, pw_ref[1]))
-    tag_ref[0, 0] = _sum_mod_all(terms)
+    tag_ref[0] = _sum_mod(terms, 0)
 
 
 def _otp_mac_edge_kernel(msg_ref, pad_ref, pw_ref, ct_ref, tag_ref):
@@ -91,13 +100,13 @@ def _otp_mac_edge_kernel(msg_ref, pad_ref, pw_ref, ct_ref, tag_ref):
     lo = (ct & MASK16) + 1          # MAC symbols (+1 padding-proof)
     hi = (ct >> 16) + 1
     terms = _addmod(_mulmod(lo, pw_ref[0, 0]), _mulmod(hi, pw_ref[0, 1]))
-    tag_ref[0, 0] = _sum_mod_all(terms)
+    tag_ref[0, 0] = _sum_mod(terms, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def otp_xor_mac_edge_blocks(msg: jax.Array, pad: jax.Array,
                             powers: jax.Array, block_rows: int = 128,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """Edge-batched entry: msg/pad (E, nb, R, 128); powers (E, 2, R, 128).
 
     Grid (E, nb) — edges × word blocks — so one kernel launch streams
@@ -106,7 +115,7 @@ def otp_xor_mac_edge_blocks(msg: jax.Array, pad: jax.Array,
     """
     E, nb, R, C = msg.shape
     assert C == 128 and R == block_rows and powers.shape == (E, 2, R, C)
-    ct, tags = pl.pallas_call(
+    ct, parts = pl.pallas_call(
         _otp_mac_edge_kernel,
         grid=(E, nb),
         in_specs=[
@@ -116,27 +125,27 @@ def otp_xor_mac_edge_blocks(msg: jax.Array, pad: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, R, C), lambda e, i: (e, i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda e, i: (e, i)),
+            pl.BlockSpec((1, 1, 1, C), lambda e, i: (e, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((E, nb, R, C), jnp.uint32),
-            jax.ShapeDtypeStruct((E, nb), jnp.uint32),
+            jax.ShapeDtypeStruct((E, nb, 1, C), jnp.uint32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(msg, pad, powers)
-    return ct, tags
+    return ct, lane_tags(parts)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def otp_xor_mac_blocks(msg: jax.Array, pad: jax.Array, powers: jax.Array,
-                       block_rows: int = 128, interpret: bool = True):
+                       block_rows: int = 128, interpret: bool | None = None):
     """msg/pad (n_blocks, R, 128) uint32; powers (2, R, 128).
 
     Returns (ct same shape, tags (n_blocks,) uint32 partial MACs).
     """
     nb, R, C = msg.shape
     assert C == 128 and R == block_rows and powers.shape == (2, R, C)
-    ct, tags = pl.pallas_call(
+    ct, parts = pl.pallas_call(
         _otp_mac_kernel,
         grid=(nb,),
         in_specs=[
@@ -146,12 +155,12 @@ def otp_xor_mac_blocks(msg: jax.Array, pad: jax.Array, powers: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, R, C), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, C), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb, R, C), jnp.uint32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((nb, 1, C), jnp.uint32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(msg, pad, powers)
-    return ct, tags[:, 0]
+    return ct, lane_tags(parts)

@@ -28,22 +28,6 @@ def _pow_mod(r, e: int):
     return acc
 
 
-def _powers_asc(r, n: int, row: int = 256):
-    """[r^1 .. r^n] mod p, two-level: a tiny scan for r^1..r^row, a tiny
-    scan for the row multipliers (r^row)^j, then ONE vectorized mulmod for
-    the outer product. Same values as ``security.mac._powers`` but ~log n
-    fewer sequential vector rounds — this table is the wrapper's dominant
-    cost at large streams."""
-    if n <= row:
-        return _powers(r, n)
-    assert n % row == 0, (n, row)
-    base = _powers(r, row)                          # r^1 .. r^row
-    r_row = base[-1]
-    top = jnp.concatenate([jnp.uint32([1]),
-                           _powers(r_row, n // row - 1)])   # (r^row)^j
-    return mulmod(top[:, None], base[None, :]).reshape(n)
-
-
 # 128 rows × 128 lanes = 16384 words/block: the 16k-word exchange in
 # bench_kernels is ONE grid step (interpret-mode step overhead dominated
 # the old 8-row tiling), and the per-block powers table stays exact-u32.
@@ -55,8 +39,8 @@ DEFAULT_BLOCK_ROWS = 128
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "interpret", "use_kernel"))
 def otp_xor_mac(msg_u32: jax.Array, pad_u32: jax.Array, r_key, s_key,
-                block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True,
-                use_kernel: bool = True):
+                block_rows: int = DEFAULT_BLOCK_ROWS,
+                interpret: bool | None = None, use_kernel: bool = True):
     """Encrypt-and-tag a flat uint32 stream.
 
     Returns (ciphertext (n,) uint32, tag uint32). The MAC is computed over
@@ -80,7 +64,7 @@ def otp_xor_mac(msg_u32: jax.Array, pad_u32: jax.Array, r_key, s_key,
 
     # per-block symbol powers: word w -> lo symbol r^(sb-2w), hi r^(sb-2w-1)
     sb = 2 * words_pb
-    pw_all = _powers_asc(r, sb)                 # r^1 .. r^sb
+    pw_all = _powers(r, sb)                     # r^1 .. r^sb
     pw_desc = pw_all[::-1]                      # r^sb .. r^1
     pw_lo = pw_desc[0::2].reshape(R, C)
     pw_hi = pw_desc[1::2].reshape(R, C)
@@ -109,7 +93,7 @@ def otp_xor_mac(msg_u32: jax.Array, pad_u32: jax.Array, r_key, s_key,
                    static_argnames=("block_rows", "interpret", "use_kernel"))
 def otp_xor_mac_edges(msgs_u32: jax.Array, pads_u32: jax.Array, r_keys,
                       s_keys, block_rows: int = DEFAULT_BLOCK_ROWS,
-                      interpret: bool = True, use_kernel: bool = True):
+                      interpret: bool | None = None, use_kernel: bool = True):
     """Edge-batched encrypt-and-tag: one launch for a whole round stage.
 
     msgs/pads (E, n) uint32 — row e is edge e's wire stream; r/s keys
@@ -133,7 +117,7 @@ def otp_xor_mac_edges(msgs_u32: jax.Array, pads_u32: jax.Array, r_keys,
 
     # per-edge symbol powers: each edge has its own evaluation point r_e
     sb = 2 * words_pb
-    pw_all = jax.vmap(lambda re: _powers_asc(re, sb))(r)     # (E, sb)
+    pw_all = jax.vmap(lambda re: _powers(re, sb))(r)         # (E, sb)
     pw_desc = pw_all[:, ::-1]                                # r^sb .. r^1
     pw_lo = pw_desc[:, 0::2].reshape(E, R, C)
     pw_hi = pw_desc[:, 1::2].reshape(E, R, C)

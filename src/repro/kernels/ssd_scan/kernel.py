@@ -30,9 +30,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
+# the MXU's default rounds f32 operands to bf16; these kernels promise
+# their f32 oracle's numbers (2e-3 / 1e-2 off at the default on a v5e)
+FULL_F32 = jax.lax.Precision.HIGHEST
+
+
+def prefix_sum_matmul(x):
+    """Inclusive prefix sum along the last axis as a lower-triangular mask
+    matmul (Mosaic has no cumsum): out[h, i] = sum_j x[h, j] · [j <= i]."""
+    q = x.shape[-1]
+    mask = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+            <= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
+    return jax.lax.dot_general(x, mask.astype(jnp.float32),
+                               (((1,), (0,)), ((), ())), precision=FULL_F32,
+                               preferred_element_type=jnp.float32)
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr,
-                *, Q: int, P: int, N: int, H: int, rep: int, nchunks: int):
+                *, Q: int, P: int, N: int, H: int, rep: int, nchunks: int,
+                prefix_sum):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -51,7 +69,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr,
         Bm, Cm = Bg, Cg
 
     dA = dt * A                                  # (H, Q) negative
-    dA_cs = jnp.cumsum(dA, axis=1)               # (H, Q)
+    dA_cs = prefix_sum(dA)                       # (H, Q)
 
     # intra-chunk: L[h,i,j] = exp(dA_cs[h,i] - dA_cs[h,j]) for i >= j
     seg = dA_cs[:, :, None] - dA_cs[:, None, :]
@@ -59,23 +77,28 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr,
            >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
     L = jnp.where(tri[None], jnp.exp(seg), 0.0)  # (H, Q, Q)
     scores = jax.lax.dot_general(Cm, Bm, (((2,), (2,)), ((0,), (0,))),
+                                 precision=FULL_F32,
                                  preferred_element_type=jnp.float32)
     mix = scores * L * dt[:, None, :]            # (H, Q, Q) weight on x_j
     y = jax.lax.dot_general(mix, x, (((2,), (1,)), ((0,), (0,))),
+                            precision=FULL_F32,
                             preferred_element_type=jnp.float32)
 
     # off-diagonal: contribution of the incoming state
     state = state_scr[...]                       # (H, P, N) f32
     decay_out = jnp.exp(dA_cs)[:, :, None]       # (H, Q, 1)
     y = y + jax.lax.dot_general(Cm, state, (((2,), (2,)), ((0,), (0,))),
+                                precision=FULL_F32,
                                 preferred_element_type=jnp.float32) * decay_out
 
     # state update
-    chunk_decay = jnp.exp(dA_cs[:, -1])[:, None, None]      # (H, 1, 1)
-    decay_states = jnp.exp(dA_cs[:, -1:] - dA_cs)           # (H, Q)
+    last = dA_cs[:, Q - 1:Q]                                # (H, 1)
+    chunk_decay = jnp.exp(last)[:, :, None]                 # (H, 1, 1)
+    decay_states = jnp.exp(last - dA_cs)                    # (H, Q)
     wB = Bm * (decay_states * dt)[:, :, None]               # (H, Q, N)
     state_scr[...] = state * chunk_decay + jax.lax.dot_general(
         x, wB, (((1,), (1,)), ((0,), (0,))),
+        precision=FULL_F32,
         preferred_element_type=jnp.float32)
 
     y_ref[0, 0] = y.astype(y_ref.dtype)
@@ -87,11 +110,13 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_bhsp(x: jax.Array, dt: jax.Array, A: jax.Array, Bv: jax.Array,
-                  Cv: jax.Array, chunk: int = 128, interpret: bool = True):
+                  Cv: jax.Array, chunk: int = 128,
+                  interpret: bool | None = None):
     """x (B, H, S, P); dt (B, H, S); A (H,); Bv/Cv (B, G, S, N) with H % G == 0.
 
     Returns (y (B, H, S, P), final_state (B, H, P, N) f32).
     """
+    interpret = resolve_interpret(interpret)
     Bb, H, S, P = x.shape
     G, N = Bv.shape[1], Bv.shape[3]
     Q = min(chunk, S)
@@ -107,8 +132,14 @@ def ssd_scan_bhsp(x: jax.Array, dt: jax.Array, A: jax.Array, Bv: jax.Array,
     C_k = Cv.reshape(Bb, G, nc, Q, N).transpose(0, 2, 1, 3, 4)
 
     y, st = pl.pallas_call(
-        functools.partial(_ssd_kernel, Q=Q, P=P, N=N, H=H, rep=rep,
-                          nchunks=nc),
+        functools.partial(
+            _ssd_kernel, Q=Q, P=P, N=N, H=H, rep=rep, nchunks=nc,
+            # interpreted, the kernel keeps the CPU oracle's own cumsum
+            # (and its rounding: chunk sums of cs differences amplify a
+            # different summation order to ~3e-5 in y); compiled, the
+            # mask matmul, which tests/test_kernels.py holds to cumsum
+            prefix_sum=(functools.partial(jnp.cumsum, axis=1) if interpret
+                        else prefix_sum_matmul)),
         grid=(Bb, nc),
         in_specs=[
             pl.BlockSpec((1, 1, H, Q, P), lambda b, c: (b, c, 0, 0, 0)),

@@ -41,7 +41,7 @@ _ssd.defvjp(_fwd, _bwd)
 
 
 def ssd_scan(xh, dt, A, Bv, Cv, chunk: int = 128, init_state=None,
-             interpret: bool = True, use_kernel: bool = True):
+             interpret: bool | None = None, use_kernel: bool = True):
     """Model-layout drop-in for blocks.ssd_ref: xh (B,S,H,P), dt (B,S,H),
     A (H,), Bv/Cv (B,S,G,N) -> (y (B,S,H,P), final_state (B,H,P,N)).
 

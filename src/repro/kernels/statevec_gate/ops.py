@@ -38,6 +38,7 @@ import logging
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.statevec_gate.kernel import (
     GROUP_QUBITS, GROUP_TILE, LOW_QUBITS, MAX_FUSED_DIM, apply_gate_planes,
     apply_layer_planes, apply_layer_planes_tiled,
@@ -98,9 +99,11 @@ _apply_planes.defvjp(_fwd, _bwd)
 
 
 def apply_gate(state: jax.Array, gate: jax.Array, qubit: int,
-               interpret: bool = True, use_kernel: bool = True) -> jax.Array:
-    """Drop-in for statevector.apply_1q on 1-D complex states (the kernel
-    path; batched states should vmap)."""
+               interpret: bool | None = None,
+               use_kernel: bool = True) -> jax.Array:
+    """Drop-in for statevector.apply_1q on (..., 2^nq) complex states (the
+    kernel path; leading batch dims fold into the grid)."""
+    interpret = resolve_interpret(interpret)
     g8 = _pack_gate(gate)
     sr = state.real.astype(jnp.float32)
     si = state.imag.astype(jnp.float32)
@@ -157,15 +160,10 @@ def _apply_layer_planes(state_re, state_im, gates8, interpret, use_kernel,
             "(low_qubits=%d, group_tile=%d) — degrading to %d per-gate "
             "kernel sweeps", state_re.shape[-1], low_qubits, group_tile,
             gates8.shape[0])
-        lead = state_re.shape[:-1]
-        sr = state_re.reshape(-1, state_re.shape[-1])
-        si = state_im.reshape(-1, state_im.shape[-1])
         for q in range(gates8.shape[0]):
-            sr, si = jax.vmap(
-                lambda a, b, g8=gates8[q], qq=q: apply_gate_planes(
-                    a, b, g8, qq, interpret=interpret))(sr, si)
-        return (sr.reshape(lead + (sr.shape[-1],)),
-                si.reshape(lead + (si.shape[-1],)))
+            state_re, state_im = apply_gate_planes(
+                state_re, state_im, gates8[q], q, interpret=interpret)
+        return state_re, state_im
     return apply_layer_planes_ref(state_re, state_im, gates8)
 
 
@@ -188,7 +186,7 @@ _apply_layer_planes.defvjp(_layer_fwd, _layer_bwd)
 
 
 def apply_gate_layer(state: jax.Array, gates: jax.Array,
-                     interpret: bool = True, use_kernel: bool = True,
+                     interpret: bool | None = None, use_kernel: bool = True,
                      low_qubits: int = LOW_QUBITS,
                      group_qubits: int = GROUP_QUBITS,
                      group_tile: int = GROUP_TILE) -> jax.Array:
@@ -201,6 +199,7 @@ def apply_gate_layer(state: jax.Array, gates: jax.Array,
     tiled multi-stage plan (one HBM pass per qubit group). The tiling
     knobs exist for tests; defaults are the production plan.
     """
+    interpret = resolve_interpret(interpret)
     g8 = _pack_gates(gates)
     sr = state.real.astype(jnp.float32)
     si = state.imag.astype(jnp.float32)

@@ -43,9 +43,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
+# the MXU's default rounds f32 operands to bf16; these kernels promise
+# their f32 oracle's numbers (2e-3 / 1e-2 off at the default on a v5e)
+FULL_F32 = jax.lax.Precision.HIGHEST
+
 NEG_INF = -1e30
 BLOCK_BH = 8       # batch·head rows folded into one block
 MAX_BAND_STEPS = 4  # widest band (in BK blocks) kept fully VMEM-resident
+# full-f32 matmuls split their operands in VMEM: the band-resident plan
+# at BLOCK_BH=8, BQ=256 outgrows the default 16 MiB scoped VMEM of a v5e
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
 def kv_steps(S: int, W: int, BQ: int, BK: int) -> int:
@@ -80,6 +89,7 @@ def _fwd_kernel(*refs, BQ: int, BK: int, W: int, nkv: int, scale: float):
         mask &= jnp.broadcast_to(raw_kb >= 0, mask.shape)
         k = k_refs[j][...].astype(jnp.float32)          # (BBH, BK, hd)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                precision=FULL_F32,
                                 preferred_element_type=jnp.float32)
         scores.append(jnp.where(mask[None], s, NEG_INF))
 
@@ -90,6 +100,7 @@ def _fwd_kernel(*refs, BQ: int, BK: int, W: int, nkv: int, scale: float):
     v = jnp.concatenate([vr[...].astype(jnp.float32) for vr in v_refs],
                         axis=1)                         # (BBH, nkv·BK, hd)
     o = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+                            precision=FULL_F32,
                             preferred_element_type=jnp.float32)
     o_ref[...] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -120,6 +131,7 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                            precision=FULL_F32,
                             preferred_element_type=jnp.float32)
     s = jnp.where(mask[None], s, NEG_INF)
 
@@ -130,6 +142,7 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=2, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v, (((2,), (1,)), ((0,), (0,))),
+        precision=FULL_F32,
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
@@ -143,9 +156,11 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                                              "interpret"))
 def swa_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
                        window: int = 0, block_q: int = 256,
-                       block_k: int = 128, interpret: bool = True):
+                       block_k: int = 128,
+                       interpret: bool | None = None):
     """q/k/v (BH, S, hd) — heads folded into batch, kv pre-repeated for GQA.
     Returns o (BH, S, hd)."""
+    interpret = resolve_interpret(interpret)
     BH, S, hd = q.shape
     # degrade block sizes to divisors of S (e.g. S=384 -> BQ 256->128);
     # the band formula and qb_last anchor additionally need the q and kv
@@ -184,6 +199,7 @@ def swa_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
             out_specs=pl.BlockSpec((bbh, BQ, hd),
                                    lambda bh, qi: (bh, qi, 0)),
             out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
+            compiler_params=COMPILER_PARAMS,
             interpret=interpret,
         )(q, *([k] * nkv), *([v] * nkv))
 
@@ -208,5 +224,6 @@ def swa_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bbh, BQ, 1), jnp.float32),
             pltpu.VMEM((bbh, BQ, hd), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v)

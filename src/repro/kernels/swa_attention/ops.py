@@ -58,7 +58,7 @@ _swa.defvjp(_swa_fwd, _swa_bwd)
 
 
 def swa_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int = 0,
-                  interpret: bool = True, use_kernel: bool = True):
+                  interpret: bool | None = None, use_kernel: bool = True):
     """q (B, S, H, hd); k/v (B, S, KV, hd) GQA -> o (B, S, H, hd)."""
     B, S, H, hd = q.shape
     k = _repeat_kv(k, H)

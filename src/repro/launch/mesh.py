@@ -15,18 +15,26 @@ are ISL traffic, the "pod" axis is the primary→ground feeder tier.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes GSPMD partitions from the in/out shardings and
+    sharding constraints (``jax.make_mesh`` now defaults to Explicit
+    axes, which this code base does not use)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Degenerate mesh on the real local device(s) — smoke tests, examples."""
     n = len(jax.devices())
-    return jax.make_mesh((max(n // model, 1), model), ("data", "model"))
+    return _auto_mesh((max(n // model, 1), model), ("data", "model"))
 
 
 def data_axes_for(mesh) -> tuple:

@@ -137,13 +137,17 @@ def run_fl_host(args, cfg, api, fl, trace, sats, server):
 def run_fl(args):
     from repro.constellation import build_trace
     from repro.core import SatQFLConfig, compile_round_plan
-    from repro.core.dist import fl_init_state, make_fl_round
+    from repro.core.dist import (fl_init_state, make_fl_round,
+                                 sample_round_batches, sat_axis_shardings,
+                                 stack_client_data)
+    from repro.launch.mesh import make_host_mesh
     from repro.data import make_statlog, dirichlet_partition, server_split
     from repro.models import get_config, get_model
     from repro.nn.optim import sgd
 
-    cfg = get_config("vqc-satqfl").replace(
-        vqc_qubits=args.qubits, vqc_layers=2, n_features=args.qubits)
+    cfg = get_config("vqc-satqfl")
+    if args.qubits:
+        cfg = cfg.replace(vqc_qubits=args.qubits, n_features=args.qubits)
     api = get_model(cfg)
     n_sats = args.sats
     if args.engine == "dist":
@@ -166,7 +170,7 @@ def run_fl(args):
                       local_steps=args.local_steps,
                       batch_size=args.batch, lr=args.lr, seed=args.seed,
                       security=security)
-    X, y = make_statlog(n_features=args.qubits)
+    X, y = make_statlog(n_features=cfg.n_features)
     Xc, yc, server = server_split(X, y)
     sats = dirichlet_partition(Xc, yc, n_sats)
     trace = build_trace(n_sats=n_sats, n_planes=max(n_sats // 2, 1),
@@ -176,13 +180,22 @@ def run_fl(args):
 
     opt = sgd(fl.lr)
     state = fl_init_state(cfg, api, opt, n_sats, jax.random.PRNGKey(args.seed))
+    data = stack_client_data(sats)
+    n_dev = len(jax.devices())
+    if n_dev > 1:
+        # the stacked satellite axis is the data-parallel axis of the
+        # host mesh: each device trains its slice of the constellation
+        if n_sats % n_dev:
+            raise SystemExit(f"--sats {n_sats} must be a multiple of the "
+                             f"{n_dev} devices")
+        mesh = make_host_mesh()
+        state = jax.device_put(state, sat_axis_shardings(state, mesh))
+        data = jax.device_put(data, sat_axis_shardings(data, mesh))
     seq_hops = 4
     round_fn = jax.jit(make_fl_round(cfg, api, fl, opt, n_sats,
                                      security=args.security,
                                      seq_hops=seq_hops))
-    per = min(len(s["features"]) for s in sats)
-    E, Bn = fl.local_steps, fl.batch_size
-    steps = E * seq_hops if fl.mode == "seq" else E
+    steps = fl.local_steps * (seq_hops if fl.mode == "seq" else 1)
 
     # participation masks, pad seeds and FedAvg weights all come from the
     # compiled constellation schedule — not invented here
@@ -192,13 +205,10 @@ def run_fl(args):
 
     rng = np.random.default_rng(args.seed)
     print(f"[fl] mode={fl.mode} security={args.security} sats={n_sats} "
+          f"devices={n_dev} "
           f"(plan: {plan.participants(0)}/{n_sats} participate at r0)")
     for r in range(args.rounds):
-        idx = rng.integers(0, per, (n_sats, steps, Bn))
-        batches = {
-            "features": jnp.stack([s["features"][i] for s, i in zip(sats, idx)]),
-            "labels": jnp.stack([s["labels"][i] for s, i in zip(sats, idx)]),
-        }
+        batches = sample_round_batches(data, rng, steps, fl.batch_size)
         mask, seeds, weights = plan.dist_inputs(r)
         state, metrics = round_fn(state, batches, mask, seeds, weights)
         # server metrics on the aggregated model (satellite 0's copy)
@@ -211,6 +221,8 @@ def run_fl(args):
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true")
@@ -235,8 +247,11 @@ def main(argv=None):
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--sats", type=int, default=8)
     ap.add_argument("--local-steps", type=int, default=5)
-    ap.add_argument("--qubits", type=int, default=6)
+    ap.add_argument("--qubits", type=int, default=None,
+                    help="override the VQC width (default: vqc-satqfl as "
+                         "configured, 8 qubits)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.fl:
         run_fl(args)
     else:

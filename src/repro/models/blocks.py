@@ -498,7 +498,6 @@ def _moe_replicated_ep(xt, p, cfg: ArchConfig, ctx):
     """Tokens replicated across the model axis; each rank computes its
     expert slice; psum combines. Used for small token counts (decode)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     e_num = cfg.n_experts // ctx.model_size
     dp = ctx.data_spec_axes
@@ -514,8 +513,8 @@ def _moe_replicated_ep(xt, p, cfg: ArchConfig, ctx):
                 P(ctx.model_axis, None, None),
                 P(ctx.model_axis, None, None),
                 P(ctx.model_axis, None, None))
-    return shard_map(local_fn, mesh=ctx.mesh, in_specs=specs_in,
-                     out_specs=(P(dp, None), P()), check_rep=False)(
+    return jax.shard_map(local_fn, mesh=ctx.mesh, in_specs=specs_in,
+                         out_specs=(P(dp, None), P()), check_vma=False)(
         xt, p["router"], p["we_g"], p["we_u"], p["we_d"])
 
 
@@ -526,7 +525,6 @@ def _moe_a2a(x3, p, cfg: ArchConfig, ctx):
     "model" (the seq-sharded residual layout) — shards flatten locally.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     ms = ctx.model_size
     e_num = cfg.n_experts // ms
@@ -608,9 +606,9 @@ def _moe_a2a(x3, p, cfg: ArchConfig, ctx):
                 P(ctx.model_axis, None, None),
                 P(ctx.model_axis, None, None),
                 P(ctx.model_axis, None, None))
-    return shard_map(local_fn, mesh=ctx.mesh, in_specs=specs_in,
-                     out_specs=(P(dp, ctx.model_axis, None), P()),
-                     check_rep=False)(
+    return jax.shard_map(local_fn, mesh=ctx.mesh, in_specs=specs_in,
+                         out_specs=(P(dp, ctx.model_axis, None), P()),
+                         check_vma=False)(
         x3, p["router"], p["we_g"], p["we_u"], p["we_d"])
 
 

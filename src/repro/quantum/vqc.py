@@ -61,7 +61,7 @@ def layer_gates(params) -> jax.Array:
     """
     ry = sv.ry_gate(params["theta"])          # gate builders broadcast
     rz = sv.rz_gate(params["phi"])
-    return jnp.einsum("...ab,...bc->...ac", rz, ry)
+    return jnp.einsum("...ab,...bc->...ac", rz, ry, precision=sv.HIGHEST)
 
 
 def encoding_gates(cfg: ArchConfig, features: jax.Array) -> jax.Array:
@@ -212,7 +212,7 @@ def parameter_shift_grad(cfg: ArchConfig, params, batch, chunk: int = 0,
     # chained diagonal observable: Σ_shift dL·logits needs only
     # Σ_{b,i} |ψ|²[b,i] · M[b,i] per branch (b_out cancels in the ± diff)
     M = jnp.einsum("bc,ci->bi", dL * params["w_out"],
-                   sv.zexp_signs(nq, cfg.n_classes))
+                   sv.zexp_signs(nq, cfg.n_classes), precision=sv.HIGHEST)
 
     half = jnp.pi / 2
     ry_pm = jnp.stack([sv.ry_gate(half), sv.ry_gate(-half)])    # (2, 2, 2)
@@ -223,7 +223,8 @@ def parameter_shift_grad(cfg: ArchConfig, params, batch, chunk: int = 0,
         def suffix(st):
             for l in range(l0, L):
                 st = sv.apply_1q_layer(st, gates[l], group) * ring
-            return jnp.einsum("...bi,bi->...", sv.probs(st), M)
+            return jnp.einsum("...bi,bi->...", sv.probs(st), M,
+                              precision=sv.HIGHEST)
         if chunk and chunk > 0:
             flat = stack.reshape((-1,) + stack.shape[2:])
             return jax.lax.map(suffix, flat,

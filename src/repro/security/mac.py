@@ -64,9 +64,13 @@ def _sum_mod(v: jax.Array) -> jax.Array:
 
 
 def _powers(r: jax.Array, n: int) -> jax.Array:
-    """[r^1, r^2, ..., r^n] mod p via associative scan (parallel prefix)."""
-    rs = jnp.broadcast_to(r.astype(jnp.uint32), (n,))
-    return jax.lax.associative_scan(mulmod, rs)
+    """[r^1, r^2, ..., r^n] mod p by doubling: r^1..r^k times r^k is
+    r^(k+1)..r^(2k), so log2(n) vectorized mulmod steps. (A batched
+    ``associative_scan`` of ``mulmod`` takes XLA:CPU minutes to compile.)"""
+    pw = jnp.reshape(r.astype(jnp.uint32), (1,))
+    while pw.shape[0] < n:
+        pw = jnp.concatenate([pw, mulmod(pw, pw[-1])])
+    return pw[:n]
 
 
 def poly_mac_u32(msg_u32: jax.Array, r_key: jax.Array, s_key: jax.Array) -> jax.Array:

@@ -185,6 +185,18 @@ def test_ssd_matches_ref(S, G, Hg, P, N, chunk):
     np.testing.assert_allclose(np.asarray(st_k), np.asarray(st_r), atol=3e-5)
 
 
+@pytest.mark.parametrize("q", [32, 128])
+def test_ssd_prefix_sum_matmul_matches_cumsum(q):
+    """The compiled kernel's prefix sum (a mask matmul: Mosaic has no
+    cumsum) is cumsum up to f32 summation order."""
+    from repro.kernels.ssd_scan.kernel import prefix_sum_matmul
+    key = jax.random.PRNGKey(q)
+    dA = -jax.nn.softplus(jax.random.normal(key, (8, q)))
+    np.testing.assert_allclose(np.asarray(prefix_sum_matmul(dA)),
+                               np.asarray(jnp.cumsum(dA, axis=1)),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_ssd_chunk_invariance():
     """The chunked algorithm must be exact: different chunk sizes agree."""
     B, S, H, G, P, N = 1, 128, 2, 1, 16, 32

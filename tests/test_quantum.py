@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")  # optional dev dep
-from hypothesis import given, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.models import get_config
 from repro.quantum import (

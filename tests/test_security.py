@@ -275,5 +275,5 @@ if HAVE_HYPOTHESIS:
         r, s = jnp.uint32(123), jnp.uint32(456)
         tag = poly_mac_u32(msg, r, s)
         i = pos % 256
-        tampered = msg.at[i].set(msg[i] ^ (1 << bit))
+        tampered = msg.at[i].set(msg[i] ^ jnp.uint32(1 << bit))
         assert not bool(mac_verify(tampered, tag, r, s))
