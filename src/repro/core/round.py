@@ -69,6 +69,7 @@ exactly equal, and QBER aborts / MAC failures surface per edge
 from __future__ import annotations
 
 from dataclasses import asdict, dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -103,10 +104,119 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # receiver-side batched MAC check — module-level so tests can simulate a
-# tampered stage. NOTE: it is read at TRACE time of _secure_stage_impl, so
-# a patch only takes effect for trainers that have not yet run a secure
-# stage (patch before the first run_round)
+# tampered stage. NOTE: it is read at TRACE time of _secure_stage_impl,
+# whose compiled program every trainer shares, so a patch takes effect
+# only between two _secure_stage_impl.clear_cache() calls
 _mac_rows_verify = mac_verify_rows
+
+
+# ----------------------------------------------------------------------
+# stages that read nothing of a trainer but static settings: jitted once
+# here, so every trainer of the same shapes and settings reuses one
+# compiled program (a jit of a bound method compiles again per instance)
+# ----------------------------------------------------------------------
+@partial(jax.jit, static_argnames="verify_mac")
+def _secure_stage_impl(stacked, seeds, mac_r, mac_s, tamper, *,
+                       verify_mac: bool):
+    """ONE edge-batched Algorithm-2 dispatch over the dispatch frame:
+    per-row pad expansion + OTP-XOR (encrypt), stacked wire streams,
+    batched MAC tag + verify, decrypt. Rows without an edge carry seed
+    0 and pass through bit-identically (XOR is an involution).
+    ``tamper`` holds the fault plane's injected wire-corruption word
+    per row (0 = clean): it flips the first RECEIVED word before the
+    receiver's MAC recompute, so tampered rows genuinely fail
+    verification in-dispatch."""
+    ct = encrypt_tree_rows(stacked, seeds)
+    if verify_mac:
+        streams = tree_to_u32_rows(ct)
+        tags = poly_mac_rows(streams, mac_r, mac_s)
+        # receiver-side recompute over the received streams
+        rx = streams.at[:, 0].set(streams[:, 0] ^ tamper)
+        ok = _mac_rows_verify(rx, tags, mac_r, mac_s)
+    else:
+        ok = jnp.ones((seeds.shape[0],), bool)
+    return decrypt_tree_rows(ct, seeds), ok
+
+
+# async v2: the batched executor's ring dispatches, the oracle's masking
+# and frame merge
+@jax.jit
+def _ring_send_impl(ring, rows, sats, slots):
+    """Scatter this round's trained updates into their ring slots
+    (born mod D); masked rows land on the scratch satellite row."""
+    return jax.tree_util.tree_map(
+        lambda full, x: full.at[sats, slots].set(x), ring, rows)
+
+
+@jax.jit
+def _ring_send_y_impl(ring_y, rows, sats, slots, wq, seeds, signs):
+    """secagg send: quantize + pairwise-mask every row, then scatter —
+    one dispatch for the whole cohort."""
+    y = jax.vmap(secagg_mask_stream)(rows, wq, seeds, signs)
+    return ring_y.at[sats, slots].set(y)
+
+
+# the oracle masks one satellite's update at a time
+_mask_one = jax.jit(secagg_mask_stream)
+
+
+@jax.jit
+def _async_merge_impl(ring, mw, anyv, gparams):
+    """The entire async merge as one masked tensordot over the ring
+    frame: mw (mp, N+1, D) holds the plan's normalized weights (zero
+    = cell not in this round's merge), anyv masks empty mains back to
+    the global model."""
+    mp = mw.shape[0]
+    nd = mw.shape[1] * mw.shape[2]
+    w2 = mw.reshape(mp, nd)
+
+    def one(x, g):
+        xf = x.reshape((nd,) + x.shape[2:]).astype(jnp.float32)
+        xb = jnp.broadcast_to(xf[None], (mp,) + xf.shape)
+        out = jnp.einsum('gk,gk...->g...', w2, xb, precision=HIGHEST)
+        k = anyv.reshape((-1,) + (1,) * (out.ndim - 1))
+        return jnp.where(k, out,
+                         g.astype(jnp.float32)[None]).astype(x.dtype)
+
+    return jax.tree_util.tree_map(one, ring, gparams)
+
+
+@jax.jit
+def _amerge_frame_impl(entries, rows, wf):
+    """Oracle-side merge: scatter the per-main list into the SAME
+    (N+1)·D frame and run the identical einsum — zero-weight cells
+    are exact no-ops, so this is bit-equal to the ring dispatch."""
+    nd = wf.shape[0]
+
+    def one(x):
+        frame = jnp.zeros((nd,) + x.shape[1:], x.dtype).at[rows].set(x)
+        return jnp.einsum('k,k...->...', wf, frame.astype(jnp.float32),
+                          precision=HIGHEST).astype(x.dtype)
+
+    return jax.tree_util.tree_map(one, entries)
+
+
+@jax.jit
+def _async_merge_y_impl(ring_y, sel, corr_seed, corr_sign, sumw, anyv,
+                        gparams):
+    """secagg merge: masked mod-2^32 sum over the ring + the plan's
+    signed correction streams (absent partners' pads cancelled),
+    then dequantize — one dispatch over the stacked main axis."""
+    mp = sel.shape[0]
+    nd = sel.shape[1] * sel.shape[2]
+    yf = ring_y.reshape(nd, -1)
+    agg = jnp.sum(sel.reshape(mp, nd)[:, :, None] * yf[None],
+                  axis=1, dtype=jnp.uint32)
+    corr = jax.vmap(
+        lambda sd, sg: sum_signed_pads(sd, sg, yf.shape[-1]))(
+        corr_seed, corr_sign)
+    merged = q32_to_tree(agg + corr, gparams, sumw)
+
+    def keep(m, g):
+        k = anyv.reshape((-1,) + (1,) * (m.ndim - 1))
+        return jnp.where(k, m, g[None]).astype(g.dtype)
+
+    return jax.tree_util.tree_map(keep, merged, gparams)
 
 
 def default_sample_batch(data: dict, key, batch_size: int) -> dict:
@@ -256,7 +366,6 @@ class SatQFLTrainer:
         self._batched_train = make_batched_local_train(api, model_cfg, fl,
                                                        self.opt)
         self._jit_stage = jax.jit(self._batched_stage_impl)
-        self._jit_secure = jax.jit(self._secure_stage_impl)
         self._jit_dev_eval = jax.jit(self._dev_eval_impl)
         # the whole schedule — roles, assignments, participation, window
         # waits, FedAvg weights, and the secure-exchange EdgeSchedule — is
@@ -277,7 +386,7 @@ class SatQFLTrainer:
             self._init_async()
 
     def _init_async(self):
-        """Async v2 state: the device-side staleness ring and its jits.
+        """Async v2 state: the device-side staleness ring.
 
         The ring is keyed (satellite, born mod D) — row ``n_sats`` is the
         scratch row absorbing masked scatter writes — so group reshuffles
@@ -291,9 +400,6 @@ class SatQFLTrainer:
         arr_max = max((int(es.ptr[r, 1] - es.ptr[r, 0])
                        for r in range(self.plan.n_rounds)), default=1)
         self._async_exframe = _next_pow2(max(arr_max, 1))
-        self._jit_ring_send = jax.jit(self._ring_send_impl)
-        self._jit_async_merge = jax.jit(self._async_merge_impl)
-        self._jit_amerge_frame = jax.jit(self._amerge_frame_impl)
         self._ring = jax.tree_util.tree_map(
             lambda x: jnp.zeros((N + 1, D) + x.shape, x.dtype),
             self.global_params)
@@ -310,9 +416,6 @@ class SatQFLTrainer:
                     "secagg wire stream size disagrees with the model's "
                     "byte accounting")
             self._ring_y = jnp.zeros((N + 1, D, self._q_words), jnp.uint32)
-            self._jit_ring_send_y = jax.jit(self._ring_send_y_impl)
-            self._jit_async_merge_y = jax.jit(self._async_merge_y_impl)
-            self._jit_mask_one = jax.jit(secagg_mask_stream)
 
     # ------------------------------------------------------------------
     # local training
@@ -599,26 +702,6 @@ class SatQFLTrainer:
             return params, t
         raise ValueError(fl.security)
 
-    def _secure_stage_impl(self, stacked, seeds, mac_r, mac_s, tamper):
-        """ONE edge-batched Algorithm-2 dispatch over the dispatch frame:
-        per-row pad expansion + OTP-XOR (encrypt), stacked wire streams,
-        batched MAC tag + verify, decrypt. Rows without an edge carry seed
-        0 and pass through bit-identically (XOR is an involution).
-        ``tamper`` holds the fault plane's injected wire-corruption word
-        per row (0 = clean): it flips the first RECEIVED word before the
-        receiver's MAC recompute, so tampered rows genuinely fail
-        verification in-dispatch."""
-        ct = encrypt_tree_rows(stacked, seeds)
-        if self.fl.verify_mac:
-            streams = tree_to_u32_rows(ct)
-            tags = poly_mac_rows(streams, mac_r, mac_s)
-            # receiver-side recompute over the received streams
-            rx = streams.at[:, 0].set(streams[:, 0] ^ tamper)
-            ok = _mac_rows_verify(rx, tags, mac_r, mac_s)
-        else:
-            ok = jnp.ones((seeds.shape[0],), bool)
-        return decrypt_tree_rows(ct, seeds), ok
-
     def _exchange_rows_batched(self, stacked, rows, edges, r: int,
                                stage: int, link: str, conc, borns=None):
         """Edge-batched Algorithm 2 for one round stage.
@@ -728,9 +811,10 @@ class SatQFLTrainer:
                 mr[row] = es.mac_r[r, lo + j]
                 ms[row] = es.mac_s[r, lo + j]
                 tam[row] = tampv[j]
-        out, ok = self._jit_secure(stacked, jnp.asarray(seeds),
-                                   jnp.asarray(mr), jnp.asarray(ms),
-                                   jnp.asarray(tam))
+        out, ok = _secure_stage_impl(stacked, jnp.asarray(seeds),
+                                     jnp.asarray(mr), jnp.asarray(ms),
+                                     jnp.asarray(tam),
+                                     verify_mac=fl.verify_mac)
         if fl.verify_mac and live_rows:
             ok = np.asarray(ok)
             bad = [canonical_edge(e) for row, e in live_rows if not ok[row]]
@@ -920,7 +1004,7 @@ class SatQFLTrainer:
                 if rd < 0:
                     continue    # windowless / stale-on-arrival / horizon
                 if secagg:
-                    p = {"y": self._jit_mask_one(
+                    p = {"y": _mask_one(
                         p, jnp.int32(int(st.wq[s])),
                         jnp.asarray(st.pair_seed[r, s]),
                         jnp.asarray(st.pair_sign[r, s]))}
@@ -958,7 +1042,7 @@ class SatQFLTrainer:
                     rows.append(pos)
                 stacked = jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs), *[p for p, _, _ in fresh])
-                state["merged"][m] = self._jit_amerge_frame(
+                state["merged"][m] = _amerge_frame_impl(
                     stacked, jnp.asarray(rows), jnp.asarray(wf))
         self._async_state = state
 
@@ -1018,74 +1102,6 @@ class SatQFLTrainer:
         merged = jax.tree_util.tree_map(_merge, p, self._broadcast_global(mp))
         return merged, group_walls, [0.0], int(sum(delivered))
 
-    # ------------------------------------------------------------------
-    # async v2 ring dispatches (batched executor)
-    # ------------------------------------------------------------------
-    def _ring_send_impl(self, ring, rows, sats, slots):
-        """Scatter this round's trained updates into their ring slots
-        (born mod D); masked rows land on the scratch satellite row."""
-        return jax.tree_util.tree_map(
-            lambda full, x: full.at[sats, slots].set(x), ring, rows)
-
-    def _ring_send_y_impl(self, ring_y, rows, sats, slots, wq, seeds, signs):
-        """secagg send: quantize + pairwise-mask every row, then scatter —
-        one dispatch for the whole cohort."""
-        y = jax.vmap(secagg_mask_stream)(rows, wq, seeds, signs)
-        return ring_y.at[sats, slots].set(y)
-
-    def _async_merge_impl(self, ring, mw, anyv, gparams):
-        """The entire async merge as one masked tensordot over the ring
-        frame: mw (mp, N+1, D) holds the plan's normalized weights (zero
-        = cell not in this round's merge), anyv masks empty mains back to
-        the global model."""
-        mp = mw.shape[0]
-        nd = mw.shape[1] * mw.shape[2]
-        w2 = mw.reshape(mp, nd)
-
-        def one(x, g):
-            xf = x.reshape((nd,) + x.shape[2:]).astype(jnp.float32)
-            xb = jnp.broadcast_to(xf[None], (mp,) + xf.shape)
-            out = jnp.einsum('gk,gk...->g...', w2, xb, precision=HIGHEST)
-            k = anyv.reshape((-1,) + (1,) * (out.ndim - 1))
-            return jnp.where(k, out,
-                             g.astype(jnp.float32)[None]).astype(x.dtype)
-
-        return jax.tree_util.tree_map(one, ring, gparams)
-
-    def _amerge_frame_impl(self, entries, rows, wf):
-        """Oracle-side merge: scatter the per-main list into the SAME
-        (N+1)·D frame and run the identical einsum — zero-weight cells
-        are exact no-ops, so this is bit-equal to the ring dispatch."""
-        nd = wf.shape[0]
-
-        def one(x):
-            frame = jnp.zeros((nd,) + x.shape[1:], x.dtype).at[rows].set(x)
-            return jnp.einsum('k,k...->...', wf, frame.astype(jnp.float32),
-                              precision=HIGHEST).astype(x.dtype)
-
-        return jax.tree_util.tree_map(one, entries)
-
-    def _async_merge_y_impl(self, ring_y, sel, corr_seed, corr_sign, sumw,
-                            anyv, gparams):
-        """secagg merge: masked mod-2^32 sum over the ring + the plan's
-        signed correction streams (absent partners' pads cancelled),
-        then dequantize — one dispatch over the stacked main axis."""
-        mp = sel.shape[0]
-        nd = sel.shape[1] * sel.shape[2]
-        yf = ring_y.reshape(nd, -1)
-        agg = jnp.sum(sel.reshape(mp, nd)[:, :, None] * yf[None],
-                      axis=1, dtype=jnp.uint32)
-        corr = jax.vmap(
-            lambda sd, sg: sum_signed_pads(sd, sg, yf.shape[-1]))(
-            corr_seed, corr_sign)
-        merged = q32_to_tree(agg + corr, gparams, sumw)
-
-        def keep(m, g):
-            k = anyv.reshape((-1,) + (1,) * (m.ndim - 1))
-            return jnp.where(k, m, g[None]).astype(g.dtype)
-
-        return jax.tree_util.tree_map(keep, merged, gparams)
-
     def _merge_async_batched(self, r: int, mains: list, groups: dict,
                              mp: int):
         """Async v2 round: train (one dispatch), scatter-into-ring (one
@@ -1124,11 +1140,11 @@ class SatQFLTrainer:
                     wq[j] = st.wq[s]
                     seeds[j] = st.pair_seed[r, s]
                     signs[j] = st.pair_sign[r, s]
-                self._ring_y = self._jit_ring_send_y(
+                self._ring_y = _ring_send_y_impl(
                     self._ring_y, p, jnp.asarray(sats), jnp.asarray(slots),
                     jnp.asarray(wq), jnp.asarray(seeds), jnp.asarray(signs))
             else:
-                self._ring = self._jit_ring_send(
+                self._ring = _ring_send_impl(
                     self._ring, p, jnp.asarray(sats), jnp.asarray(slots))
         # arrivals: updates whose window has opened by this round (the
         # plan's stage-0 edge list IS the delivery schedule)
@@ -1168,16 +1184,15 @@ class SatQFLTrainer:
             cg[:st.n_mains_max] = st.corr_sign[r]
             sw = np.zeros((mp,), np.float32)
             sw[:st.n_mains_max] = st.sum_wq[r]
-            merged = self._jit_async_merge_y(
+            merged = _async_merge_y_impl(
                 self._ring_y, jnp.asarray(sel), jnp.asarray(cs),
                 jnp.asarray(cg), jnp.asarray(sw), jnp.asarray(anyv),
                 self.global_params)
         else:
             mw = np.zeros((mp, N + 1, D), np.float32)
             mw[:st.n_mains_max] = st.merge_w[r]
-            merged = self._jit_async_merge(self._ring, jnp.asarray(mw),
-                                           jnp.asarray(anyv),
-                                           self.global_params)
+            merged = _async_merge_impl(self._ring, jnp.asarray(mw),
+                                       jnp.asarray(anyv), self.global_params)
         if self.async_debug:
             for g, m in enumerate(mains):
                 self.async_merge_log.append(

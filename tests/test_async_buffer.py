@@ -15,9 +15,10 @@ oracle (``batched=False``):
     component per round (``CommLog.round_details``);
   * identical QBER-abort sets;
 
-for both gradient rules (the param-shift half is `slow`). End-of-round
-global parameters inherit the repo's established vmap-vs-loop contract
-(≤ 1e-6 float accumulation through mains training + global FedAvg).
+for both gradient rules. The oracle
+replays the batched executor's local training, each input checked to the
+bit, so both buffers see identical updates; end-of-round global
+parameters are held to the repo's 1e-6 contract.
 
 Plus: the compiled delay/deliver/staleness semantics pinned on crafted
 windows, the wait-accounting fix (a windowless sender clamps to the comm
@@ -124,16 +125,51 @@ def pattern(name: str, R: int = 4):
     return sg, ss
 
 
+def _record_training(tr, trained):
+    """Record what the batched executor trains, per (round, satellite):
+    (input params, trained params, loss)."""
+    real = tr._train_group_batched
+
+    def train(sat_ids, params_stacked, r, *args, **kw):
+        p, losses = real(sat_ids, params_stacked, r, *args, **kw)
+        for j, s in enumerate(sat_ids):
+            trained[(r, s)] = tuple(
+                jax.tree_util.tree_map(lambda x: np.asarray(x[j]), t)
+                for t in (params_stacked, p)) + (float(losses[j]),)
+        return p, losses
+
+    tr._train_group_batched = train
+
+
+def _replay_training(tr, trained):
+    """The oracle trains nothing itself: each (round, satellite) gets the
+    batched executor's result, after its input is checked to the bit, so
+    both paths buffer, merge and aggregate identical inputs. Vmapped and
+    per-client training agree only to the 1e-6 contract (not to the bit
+    under jax 0.9's XLA:CPU); test_batched_round and test_faults hold the
+    two trainings to it."""
+    def train(sat, params, r):
+        given, out, loss = trained[(r, sat)]
+        for a, b in zip(jax.tree_util.tree_leaves(params),
+                        jax.tree_util.tree_leaves(given)):
+            # unequal inputs: an earlier merge or aggregation differs
+            assert np.array_equal(np.asarray(a), b), ("input", r, sat)
+        return jax.tree_util.tree_map(jnp.asarray, out), loss
+
+    tr._train_sat = train
+
+
 def run_pair(model, fl, sg, ss, *, eav=frozenset(), step_s=60.0,
              equal_sizes=False, seed=0):
     cfg, api = model
     trace = make_trace(sg, ss, step_s)
     sats, server = make_data(trace.n_sats, seed, equal_sizes)
-    out = {}
-    for batched in (False, True):
+    out, trained = {}, {}
+    for batched in (True, False):
         tr = SatQFLTrainer(cfg, api, fl, trace, sats, server,
                            batched=batched, eavesdrop_edges=eav)
         tr.async_debug = True
+        (_record_training if batched else _replay_training)(tr, trained)
         hist = tr.run()
         out[batched] = (tr, hist)
     return out
@@ -182,7 +218,6 @@ def test_patterns_autodiff(model, name):
     assert_paths_agree(run_pair(model, _fl(), sg, ss))
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("name", PATTERNS)
 def test_patterns_param_shift(model, name):
     sg, ss = pattern(name)
@@ -314,7 +349,7 @@ def test_secagg_dropout_recovery_engine(model):
 # ---------------------------------------------------------------------------
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                       # pragma: no cover - optional dep
     HAVE_HYPOTHESIS = False
@@ -357,13 +392,30 @@ if HAVE_HYPOTHESIS:
             assert out[False][0].log.round_details \
                 == out[True][0].log.round_details
 
+    def _bits(rows):
+        return np.asarray([[c == "1" for c in row] for row in rows], bool)
+
+    # Found by the search below, kept as an explicit example so that every
+    # run shows it: main 2 merges three updates (weights 0.3, 0.4, 0.3) at
+    # round 2, and on identical inputs the ring dispatch
+    # (_async_merge_impl) and the oracle's frame merge (_amerge_frame_impl)
+    # sum the (1, 2)-shaped leaves to different last bits on jax 0.9's
+    # XLA:CPU: a defect of the program, not of this test.
+    MERGE3 = (4, 1, _bits(["0000", "0001", "1111", "1010", "0100"]),
+              np.stack([_bits(["0000", "1010", "1111", "1100", "1000"]),
+                        _bits(["0000", "0000", "0111", "1110", "1111"]),
+                        _bits(["0000", "0000", "0000", "0111", "0000"]),
+                        _bits(["0000"] * 5), _bits(["0000"] * 5)]),
+              "none", False, frozenset())
+
     @settings(max_examples=8, deadline=None)
-    @given(scenarios())
+    @given(sc=scenarios())
+    @example(sc=MERGE3)
     def test_property_arbitrary_patterns(model, sc):
         _property_body(model, sc, "autodiff")
 
-    @pytest.mark.slow
     @settings(max_examples=4, deadline=None)
-    @given(scenarios())
+    @given(sc=scenarios())
+    @example(sc=MERGE3)
     def test_property_arbitrary_patterns_param_shift(model, sc):
         _property_body(model, sc, "param_shift")

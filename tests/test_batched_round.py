@@ -3,14 +3,14 @@
 The PR's acceptance tests:
 
   * batched-vs-per-client metric parity ≤ 1e-6 on ALL four scheduling
-    modes and BOTH gradient rules (the param-shift half is `slow`),
+    modes and BOTH gradient rules,
     with exact comm/participant accounting equality;
   * the security layer stays transparent and bit-identical under the
     batched executor;
   * the vectorized parameter-shift rule is vmap-safe over the stacked
     client axis (grads == per-client autodiff);
   * the tiled multi-stage fused-layer kernel matches the per-gate oracle
-    at small forced-tiling sizes (tier-1) and at 20 qubits (slow), on
+    at small forced-tiling sizes and at 20 qubits, on
     single and client-stacked states, and the per-gate fallback is
     FLAGGED, never silent.
 """
@@ -74,7 +74,6 @@ def test_batched_parity_autodiff(setup, mode):
     _parity_run(setup, mode, "autodiff")
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("mode", ["qfl", "sim", "seq", "async"])
 def test_batched_parity_param_shift(setup, mode):
     """Same parity under the hardware-faithful parameter-shift rule."""
@@ -234,7 +233,6 @@ def test_per_gate_fallback_is_flagged(rng_key, caplog):
                                np.asarray(_oracle(state, gates)), atol=2e-6)
 
 
-@pytest.mark.slow
 def test_tiled_layer_20_qubits(rng_key):
     """Acceptance: nq=20 runs the tiled multi-stage plan (no per-gate
     fallback) and matches the per-gate oracle to 1e-6."""

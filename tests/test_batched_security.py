@@ -285,15 +285,55 @@ def test_qber_abort_raise_mode(setup, edge_batched):
 
 
 # ---------------------------------------------------------------------------
-# MAC failures raise (never assert)
+# every trainer shares one compiled secure stage
 # ---------------------------------------------------------------------------
 
-def test_mac_failure_raises_security_error(setup, monkeypatch):
+def test_secure_stage_compiles_once_for_two_trainers(setup):
+    """A second trainer of the same shapes and settings reuses the first
+    one's compiled ``_secure_stage_impl``: binding the stage to a trainer
+    would compile it again for every instance (tens of seconds each on
+    XLA:CPU)."""
+    import repro.core.round as round_mod
     cfg, api, trace, sats, server = setup
     fl = SatQFLConfig(mode="sim", n_rounds=1, local_steps=2, batch_size=8,
                       security="qkd")
-    # batched plane: tamper the receiver-side stage verify
+    compiles = []
+
+    def on_event(event, duration, **kw):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and "_secure_stage_impl" in kw.get("fun_name", "")):
+            compiles.append(kw["fun_name"])
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    counts, sizes = [], []
+    try:
+        for _ in range(2):
+            tr = SatQFLTrainer(cfg, api, fl, trace, sats, server)
+            tr.run_round(0)
+            counts.append(len(compiles))
+            sizes.append(round_mod._secure_stage_impl._cache_size())
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    # the first trainer compiles the stage unless an earlier test of this
+    # process did; the second compiles nothing and adds no cache entry
+    assert counts[0] <= 1 and counts[1] == counts[0], compiles
+    assert sizes[0] >= 1 and sizes[1] == sizes[0], sizes
+
+
+# ---------------------------------------------------------------------------
+# MAC failures raise (never assert)
+# ---------------------------------------------------------------------------
+
+def test_mac_failure_raises_security_error(setup, monkeypatch, request):
+    cfg, api, trace, sats, server = setup
+    fl = SatQFLConfig(mode="sim", n_rounds=1, local_steps=2, batch_size=8,
+                      security="qkd")
+    # batched plane: tamper the receiver-side stage verify. It is read when
+    # the shared stage is traced, so drop the stage's compiled programs
+    # before the patch, and again after, so no later test runs this one
     import repro.core.round as round_mod
+    round_mod._secure_stage_impl.clear_cache()
+    request.addfinalizer(round_mod._secure_stage_impl.clear_cache)
     monkeypatch.setattr(
         round_mod, "_mac_rows_verify",
         lambda streams, tags, r, s: jnp.zeros(tags.shape, bool))

@@ -23,17 +23,30 @@ def setup():
     return cfg, api, trace, sats, server
 
 
-def _run(setup, **kw):
+def _fl(**kw):
+    return SatQFLConfig(n_rounds=2, local_steps=3, batch_size=8, **kw)
+
+
+@pytest.fixture(scope="module")
+def run(setup):
+    """(trainer, history) of one run per configuration, computed once and
+    shared by every test that reads it; no test mutates a shared run."""
     cfg, api, trace, sats, server = setup
-    fl = SatQFLConfig(n_rounds=2, local_steps=3, batch_size=8, **kw)
-    tr = SatQFLTrainer(cfg, api, fl, trace, sats, server)
-    hist = tr.run()
-    return tr, hist
+    runs = {}
+
+    def get(**kw):
+        fl = _fl(**kw)
+        if fl not in runs:
+            tr = SatQFLTrainer(cfg, api, fl, trace, sats, server)
+            runs[fl] = (tr, tr.run())
+        return runs[fl]
+
+    return get
 
 
 @pytest.mark.parametrize("mode", ["qfl", "sim", "seq", "async"])
-def test_modes_run_and_evaluate(setup, mode):
-    tr, hist = _run(setup, mode=mode)
+def test_modes_run_and_evaluate(run, mode):
+    tr, hist = run(mode=mode)
     assert len(hist) == 2
     for m in hist:
         assert np.isfinite(m.server_val_loss)
@@ -42,7 +55,7 @@ def test_modes_run_and_evaluate(setup, mode):
 
 
 @pytest.mark.parametrize("mode", ["qfl", "sim", "seq", "async"])
-def test_fused_engine_metrics_match_per_gate(setup, mode):
+def test_fused_engine_metrics_match_per_gate(setup, run, mode):
     """Acceptance: trainer metrics on every mode are unchanged (within
     float tolerance) when the VQC evaluates on the fused pipeline instead
     of the per-gate path it replaced."""
@@ -56,11 +69,8 @@ def test_fused_engine_metrics_match_per_gate(setup, mode):
         return vqc_loss(c, p, b, ctx, fused=False)
 
     api_pg = api._replace(forward=fwd_pg, loss=loss_pg)
-    fl = SatQFLConfig(n_rounds=2, local_steps=3, batch_size=8, mode=mode)
-    hists = []
-    for a in (api, api_pg):
-        tr = SatQFLTrainer(cfg, a, fl, trace, sats, server)
-        hists.append(tr.run())
+    tr = SatQFLTrainer(cfg, api_pg, _fl(mode=mode), trace, sats, server)
+    hists = [run(mode=mode)[1], tr.run()]
     for m_fused, m_pg in zip(*hists):
         np.testing.assert_allclose(m_fused.server_val_loss,
                                    m_pg.server_val_loss, atol=1e-4)
@@ -87,31 +97,31 @@ def test_param_shift_trainer_matches_autodiff(setup):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_encryption_transparent(setup):
-    t1, _ = _run(setup, mode="sim", security="none")
-    t2, _ = _run(setup, mode="sim", security="qkd")
+def test_encryption_transparent(run):
+    t1, _ = run(mode="sim", security="none")
+    t2, _ = run(mode="sim", security="qkd")
     for a, b in zip(jax.tree_util.tree_leaves(t1.global_params),
                     jax.tree_util.tree_leaves(t2.global_params)):
         assert bool(jnp.all(a == b))
 
 
-def test_security_adds_overhead(setup):
-    t1, h1 = _run(setup, mode="sim", security="none")
-    t2, h2 = _run(setup, mode="sim", security="qkd")
+def test_security_adds_overhead(run):
+    t1, h1 = run(mode="sim", security="none")
+    t2, h2 = run(mode="sim", security="qkd")
     assert t2.log.security_s > t1.log.security_s
 
 
-def test_teleport_fidelity_reported(setup):
-    _, hist = _run(setup, mode="sim", security="teleport")
+def test_teleport_fidelity_reported(run):
+    _, hist = run(mode="sim", security="teleport")
     assert hist[-1].teleport_fidelity > 0.999
 
 
-def test_qfl_baseline_fastest_comm(setup):
+def test_qfl_baseline_fastest_comm(run):
     """Paper Fig.12: flat QFL beats the hierarchical schedules on comm time
     (it ignores constellation constraints)."""
-    _, h_qfl = _run(setup, mode="qfl")
-    _, h_seq = _run(setup, mode="seq")
-    _, h_sim = _run(setup, mode="sim")
+    _, h_qfl = run(mode="qfl")
+    _, h_seq = run(mode="seq")
+    _, h_sim = run(mode="sim")
     c = lambda h: sum(m.comm_s for m in h)
     assert c(h_qfl) < c(h_seq)
     assert c(h_qfl) < c(h_sim)

@@ -23,7 +23,6 @@ def _extras(cfg, B, key):
     return {}
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("arch", CASES)
 def test_decode_matches_forward(arch, rng_key):
     cfg = smoke_variant(get_config(arch))
@@ -69,7 +68,6 @@ def test_moe_decode_capacity_semantics(rng_key):
     assert float(jnp.max(jnp.abs(full_hi - full_lo))) > 1e-6
 
 
-@pytest.mark.slow
 def test_sliding_window_ring_cache(rng_key):
     """Sliding-window decode: a model with window W must give identical
     logits whether the cache holds W slots (ring) or the full context."""
@@ -95,7 +93,6 @@ def test_sliding_window_ring_cache(rng_key):
     assert float(jnp.max(jnp.abs(ring - full))) < 1e-4
 
 
-@pytest.mark.slow
 def test_int8_kv_cache_parity(rng_key):
     """Quantized KV cache: logits within quantization tolerance, top-1
     prediction preserved (the serving §Perf lever)."""

@@ -35,7 +35,6 @@ def test_dryrun_single_pod(arch, shape, tmp_path):
     assert rec["dominant"] in ("compute", "memory", "collective")
 
 
-@pytest.mark.slow
 def test_dryrun_multi_pod(tmp_path):
     out = tmp_path / "rec.json"
     r = _run(["--arch", "whisper-tiny", "--shape", "train_4k",

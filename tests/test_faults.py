@@ -47,17 +47,30 @@ def _dense(N=5, R=4):
     return sg, ss
 
 
-def _pair(model, fl, sg, ss):
+@pytest.fixture(scope="module")
+def full_run(model):
+    """The uninterrupted run of one (configuration, executor) on the dense
+    trace of ``fl.n_rounds`` steps, computed once and shared by the
+    parity and resume tests; none of them mutates it."""
     cfg, api = model
-    trace = tab.make_trace(sg, ss)
-    sats, server = tab.make_data(trace.n_sats, 0)
-    out = {}
-    for batched in (False, True):
-        tr = SatQFLTrainer(cfg, api, fl, trace, sats, server,
-                           batched=batched)
-        tr.run()
-        out[batched] = tr
-    return out
+    runs = {}
+
+    def get(fl, batched):
+        if (fl, batched) not in runs:
+            sg, ss = _dense(R=fl.n_rounds)
+            trace = tab.make_trace(sg, ss)
+            sats, server = tab.make_data(trace.n_sats, 0)
+            tr = SatQFLTrainer(cfg, api, fl, trace, sats, server,
+                               batched=batched)
+            tr.run()
+            runs[(fl, batched)] = tr
+        return runs[(fl, batched)]
+
+    return get
+
+
+def _pair(full_run, fl):
+    return {batched: full_run(fl, batched) for batched in (False, True)}
 
 
 # ---------------------------------------------------------------------------
@@ -136,11 +149,10 @@ def test_zero_rates_compile_no_schedule(model):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("mode", ["qfl", "sim", "seq", "async"])
-def test_fault_parity_oracle_vs_batched(model, mode):
-    sg, ss = _dense(R=4)
+def test_fault_parity_oracle_vs_batched(full_run, mode):
     fl = _fl(mode=mode, security="qkd",
              max_retries=(2 if mode == "async" else 0), **FAULTS)
-    out = _pair(model, fl, sg, ss)
+    out = _pair(full_run, fl)
     to, tb = out[False], out[True]
     assert to.log.round_details == tb.log.round_details
     assert to.fault_reports == tb.fault_reports
@@ -154,9 +166,8 @@ def test_fault_parity_oracle_vs_batched(model, mode):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
 
 
-def test_fault_free_round_details_carry_no_fault_key(model):
-    sg, ss = _dense()
-    out = _pair(model, _fl(security="qkd"), sg, ss)
+def test_fault_free_round_details_carry_no_fault_key(full_run):
+    out = _pair(full_run, _fl(security="qkd"))
     for tr in out.values():
         assert tr.plan.faults is None and tr.fault_reports == []
         assert all("faults" not in d for d in tr.log.round_details)
@@ -241,13 +252,12 @@ def test_async_retransmit_recovers_without_pad_reuse(model, monkeypatch):
 # crash-resume: kill at round r, restore, bit-identical end state
 # ---------------------------------------------------------------------------
 
-def _resume_check(model, fl, batched, tmp_path, kill_at=2):
+def _resume_check(model, full_run, fl, batched, tmp_path, kill_at=2):
     cfg, api = model
     sg, ss = _dense(R=fl.n_rounds)
     trace = tab.make_trace(sg, ss)
     sats, server = tab.make_data(5, 0)
-    trA = SatQFLTrainer(cfg, api, fl, trace, sats, server, batched=batched)
-    trA.run()
+    trA = full_run(fl, batched)
     trB = SatQFLTrainer(cfg, api, fl, trace, sats, server, batched=batched)
     for r in range(kill_at):
         trB.run_round(r)
@@ -266,28 +276,39 @@ def _resume_check(model, fl, batched, tmp_path, kill_at=2):
 
 
 @pytest.mark.parametrize("batched", [False, True])
-def test_crash_resume_bit_identical_sim_faults(model, batched, tmp_path):
-    _resume_check(model, _fl(security="qkd", **FAULTS), batched, tmp_path)
+def test_crash_resume_bit_identical_sim_faults(model, full_run, batched,
+                                               tmp_path):
+    _resume_check(model, full_run, _fl(security="qkd", **FAULTS), batched,
+                  tmp_path)
 
 
 @pytest.mark.parametrize("batched", [False, True])
-def test_crash_resume_bit_identical_async_retry(model, batched, tmp_path):
+def test_crash_resume_bit_identical_async_retry(model, full_run, batched,
+                                                tmp_path):
     fl = _fl(mode="async", security="qkd", max_retries=2, **FAULTS)
-    _resume_check(model, fl, batched, tmp_path)
+    _resume_check(model, full_run, fl, batched, tmp_path)
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("batched", [False, True])
-@pytest.mark.parametrize("kw", [
+RESUME_EXTENDED = [
     dict(mode="qfl", security="qkd", **FAULTS),
     dict(mode="seq", security="qkd", **FAULTS),
     dict(mode="async", agg_security="secagg", crash_rate=0.2,
          link_flap_rate=0.2, max_retries=1, fault_seed=5),
     dict(mode="sim", security="teleport"),
     dict(mode="async", security="qkd_fernet"),
-])
-def test_crash_resume_bit_identical_extended(model, kw, batched, tmp_path):
-    _resume_check(model, _fl(**kw), batched, tmp_path)
+]
+
+
+# a batched case with a QKD exchange compiles the secure stage, which
+# takes a minute or more on XLA:CPU alone: those cases are slow
+@pytest.mark.parametrize("kw,batched", [
+    pytest.param(kw, batched, id=f"kw{i}-{batched}", marks=(
+        pytest.mark.slow,) if batched and kw.get("security") in (
+            "qkd", "qkd_fernet") else ())
+    for i, kw in enumerate(RESUME_EXTENDED) for batched in (False, True)])
+def test_crash_resume_bit_identical_extended(model, full_run, kw, batched,
+                                             tmp_path):
+    _resume_check(model, full_run, _fl(**kw), batched, tmp_path)
 
 
 def test_resume_rejects_config_mismatch(model, tmp_path):
@@ -307,14 +328,13 @@ def test_resume_rejects_config_mismatch(model, tmp_path):
         oracle.restore_round_checkpoint(str(tmp_path))
 
 
-def test_run_auto_resumes_from_checkpoint_dir(model, tmp_path):
+def test_run_auto_resumes_from_checkpoint_dir(model, full_run, tmp_path):
     cfg, api = model
     sg, ss = _dense()
     trace = tab.make_trace(sg, ss)
     sats, server = tab.make_data(5, 0)
     fl = _fl(security="qkd", **FAULTS)
-    trA = SatQFLTrainer(cfg, api, fl, trace, sats, server)
-    trA.run()
+    trA = full_run(fl, True)
     trB = SatQFLTrainer(cfg, api, fl, trace, sats, server)
     for r in range(2):
         trB.run_round(r)

@@ -181,7 +181,6 @@ def test_kernel_fused_layer_vjp_matches_sim(rng_key):
 
 # --- retiled otp_xor ---------------------------------------------------------
 
-@pytest.mark.slow
 def test_otp_xor_mac_multiblock_and_tilings_agree():
     """A stream spanning several grid steps, at the default and a narrow
     tiling: ciphertext identical; tags match the ref for EACH padded
