@@ -23,7 +23,9 @@ from repro.quantum import (
     vqc_init, vqc_logits, vqc_loss,
 )
 from repro.quantum import statevector as sv
-from repro.quantum.vqc import _circuit_state, _circuit_state_fused
+from repro.quantum.vqc import (
+    _circuit_state, _circuit_state_fused, uses_dense_layers,
+)
 
 
 def _rand_state(key, shape):
@@ -82,9 +84,11 @@ def test_readout_matrix_matches_expect_z(rng_key):
 # --- fused circuit vs per-gate ----------------------------------------------
 
 @pytest.mark.parametrize("nq,L,nf", [(4, 2, 4), (5, 1, 5), (8, 2, 8),
-                                     (3, 3, 2)])
+                                     (3, 3, 2), (8, 3, 8), (9, 1, 8)])
 def test_fused_circuit_state_matches_per_gate(rng_key, nq, L, nf):
-    """Acceptance: fused circuit state == per-gate path within 1e-6."""
+    """Acceptance: fused circuit state == per-gate path within 1e-6, on
+    the dense path (up to 8 qubits, the benchmark's 8 x 3 among them) and
+    the grouped one (9)."""
     cfg = get_config("vqc-satqfl").replace(vqc_qubits=nq, vqc_layers=L,
                                            n_features=nf)
     params = vqc_init(cfg, jax.random.fold_in(rng_key, nq))
@@ -100,10 +104,11 @@ def test_fused_circuit_state_matches_per_gate(rng_key, nq, L, nf):
 
 # --- vectorized parameter-shift ---------------------------------------------
 
-@pytest.mark.parametrize("nq,L", [(4, 2), (5, 1), (3, 3)])
+@pytest.mark.parametrize("nq,L", [(4, 2), (5, 1), (3, 3), (8, 3), (9, 1)])
 def test_vectorized_shift_matches_serial_and_autodiff(rng_key, nq, L):
     """Acceptance: the vectorized branch-stacked rule == the serial lax.map
-    rule == autodiff, and the chunked variant == the unchunked one."""
+    rule == autodiff, and the chunked variant == the unchunked one, on the
+    dense path (up to 8 qubits) and the grouped one (9)."""
     cfg = get_config("vqc-satqfl").replace(vqc_qubits=nq, vqc_layers=L,
                                            n_features=nq)
     params = vqc_init(cfg, jax.random.fold_in(rng_key, L))
@@ -124,6 +129,33 @@ def test_vectorized_shift_matches_serial_and_autodiff(rng_key, nq, L):
     for k in ("w_out", "b_out"):    # closed-form head grads
         np.testing.assert_allclose(np.asarray(g_vec[k]), np.asarray(g_auto[k]),
                                    atol=2e-5)
+
+
+@pytest.mark.parametrize("nq,path", [(3, "dense"), (8, "dense"),
+                                     (9, "grouped")])
+def test_layer_path_follows_qubit_count(nq, path):
+    """Ansatz layers run as dense matmuls up to 8 qubits and kron-grouped
+    above, by the qubit count alone: no argument of the forward, the
+    shift rule or autodiff changes the path (read from the named scope
+    each path's layers carry in the lowered program)."""
+    cfg = get_config("vqc-satqfl").replace(vqc_qubits=nq, vqc_layers=1,
+                                           n_features=3)
+    params = vqc_init(cfg, jax.random.PRNGKey(0))
+    feats = jnp.zeros((2, 3), jnp.float32)
+    batch = {"features": feats, "labels": jnp.zeros((2,), jnp.int32)}
+    programs = [lambda p: vqc_logits(cfg, p, feats),
+                lambda p: jax.grad(lambda q: vqc_loss(cfg, q, batch))(p)]
+    programs += [lambda p, g=g: _circuit_state_fused(cfg, p, feats, group=g)
+                 for g in (1, 2, 3)]
+    programs += [lambda p, g=g, c=c: parameter_shift_grad(cfg, p, batch,
+                                                          chunk=c, group=g)
+                 for g in (2, 4) for c in (0, 3)]
+    other = {"dense": "grouped", "grouped": "dense"}[path]
+    assert uses_dense_layers(nq) == (path == "dense")
+    for program in programs:
+        text = jax.jit(program).lower(params).as_text(debug_info=True)
+        assert f"vqc.{path}_layer" in text
+        assert f"vqc.{other}_layer" not in text
 
 
 # --- fused-layer Pallas kernel ----------------------------------------------
